@@ -1,5 +1,6 @@
-"""Kernel micro-benchmarks (interpret-mode correctness + XLA-path timing on
-host; on TPU these run the Pallas path)."""
+"""Kernel-adjacent micro-benchmarks on the host clock: the XLA blocked
+attention, the sequential SSD oracle and MoE dispatch. None of them calls
+the Pallas kernels in ``repro.kernels``."""
 
 from __future__ import annotations
 

@@ -16,11 +16,13 @@ import numpy as np
 
 from repro.configs import smoke_config
 from repro.data import LakeDataLoader, write_synth_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lst import LocalFS
 from repro.models.model import Model
 from repro.serve.engine import Request, ServeEngine
 from repro.train.trainer import Trainer, TrainerConfig
 
+enable_compile_cache()
 fs = LocalFS()
 root = tempfile.mkdtemp()
 

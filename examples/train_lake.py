@@ -14,6 +14,7 @@ sys.path.insert(0, "src")
 
 from repro.configs import smoke_config
 from repro.data import LakeDataLoader, write_synth_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lst import LocalFS
 from repro.models.model import Model
 from repro.train.trainer import Trainer, TrainerConfig
@@ -22,6 +23,7 @@ ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=200)
 ap.add_argument("--arch", default="yi-9b")
 args = ap.parse_args()
+enable_compile_cache()
 
 fs = LocalFS()
 root = tempfile.mkdtemp()

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 import numpy as np
 
@@ -92,6 +93,7 @@ class LSTCheckpointManager:
         adds — readers never see a mixed step).
         """
         import uuid
+        t0 = time.perf_counter()
         tag = uuid.uuid4().hex[:8]
         adds = []
         for name, leaf in _leaf_paths(pytree):
@@ -117,7 +119,8 @@ class LSTCheckpointManager:
             adds, stale, operation="checkpoint",
             extra_meta={"step": str(step), **(extra_meta or {})})
         self.telemetry.record("ckpt", self.fmt, "save",
-                              f"step {step}: {len(adds)} chunks")
+                              f"step {step}: {len(adds)} chunks",
+                              time.perf_counter() - t0)
         self._kick_sync()
         return commit
 
@@ -152,6 +155,11 @@ class LSTCheckpointManager:
 
     # --------------------------------------------------------------- restore
     def steps(self, fmt: str | None = None) -> list[int]:
+        """Steps visible through ``fmt``'s view; [] while that view does
+        not exist yet (nothing saved, or not translated to it)."""
+        fmt = fmt or self.fmt
+        if fmt != self.fmt and not FORMATS[fmt].exists(self.fs, self.base):
+            return []
         handle = self._reader(fmt)
         st = handle.snapshot()
         return sorted({int(f.partition_values["step"])
@@ -171,14 +179,11 @@ class LSTCheckpointManager:
         if hasattr(handle, "latest_extra_metadata"):
             out.update(handle.latest_extra_metadata())
         else:
-            try:
-                _, _, _, info = handle.changes(handle.current_version())
-                out.update({k: v for k, v in info.items()
-                            if isinstance(v, str)})
-                if isinstance(info.get("xtable"), dict):
-                    out.update(info["xtable"])
-            except Exception:
-                pass
+            _, _, _, info = handle.changes(handle.current_version())
+            out.update({k: v for k, v in info.items()
+                        if isinstance(v, str)})
+            if isinstance(info.get("xtable"), dict):
+                out.update(info["xtable"])
         return out
 
     def restore(self, step: int | None = None, *, fmt: str | None = None,

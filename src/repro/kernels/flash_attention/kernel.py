@@ -3,7 +3,11 @@
 Grid: (batch, q_head, q_blocks, k_blocks) — k innermost, so the online
 softmax state (m, l, acc) lives in VMEM scratch and persists across the
 k-block sweep for one q block. BlockSpecs stage (bq, dh) query tiles and
-(bk, dh) key/value tiles HBM->VMEM; dh is the MXU lane dim (128-aligned).
+(bk, dh) key/value tiles HBM->VMEM; dh is the lane dim. The kernel works
+on head-major (b, h, s, dh) arrays so the last two block dims are
+(seq tile, dh): a (…, 1, dh) head slice of the (b, s, h, dh) layout is
+not a legal TPU block. The wrapper transposes in and out (one HBM pass
+each way). The MXU consumes the inputs' own dtype with f32 accumulation.
 
 GQA is handled by the k/v index maps (kv head = q head // group) — no
 repeated KV in HBM, the repeat happens implicitly via block addressing.
@@ -48,10 +52,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # (bq, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bk, dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
+        q = q_ref[...]                                        # (bq, dh)
+        k = k_ref[...]                                        # (bk, dh)
+        v = v_ref[...]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
         qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -62,20 +67,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             if window:
                 mask &= (qpos - kpos) < window
         s = jnp.where(mask, s, NEG_INF)
+        if sk % bk:
+            # the last block reads past the key end; those rows of v hold
+            # garbage, and 0 * NaN = NaN would poison the accumulator
+            vpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            v = jnp.where(vpos < sk, v, jnp.zeros_like(v))
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                   # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + \
-            jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -94,24 +105,22 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kern = functools.partial(
         _kernel, causal=causal, window=window, softcap=softcap, scale=scale,
         bq=bq, bk=bk, nk=nk, sk=sk)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, bq, dh),
+                          lambda b_, h_, q_, k_: (b_, h_, q_, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, dh),
+                           lambda b_, h_, q_, k_: (b_, h_ // g, k_, 0))
+    out = pl.pallas_call(
         kern,
         grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, dh),
-                         lambda b_, h_, q_, k_: (b_, q_, h_, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda b_, h_, q_, k_: (b_, k_, h_ // g, 0)),
-            pl.BlockSpec((1, bk, 1, dh),
-                         lambda b_, h_, q_, k_: (b_, k_, h_ // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, dh),
-                               lambda b_, h_, q_, k_: (b_, q_, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

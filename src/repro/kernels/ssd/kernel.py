@@ -12,6 +12,12 @@ Per chunk (length Q):
 
 All contractions are (Q x n)(n x Q)/(Q x Q)(Q x p) MXU shapes with Q, n, p
 multiples of the 128-lane granule at production sizes.
+
+The wrapper folds the per-head scalars into the inputs (dt * x and
+dt * A), so every block is a 2-D tile whose last two dims are legal on
+TPU: dt * A arrives as a (1, Q) row, and the kernel forms its cumulative
+sum as both a column and a row with masked reductions over a (Q, Q) tile
+instead of a 1-D cumsum and a transpose.
 """
 
 from __future__ import annotations
@@ -23,8 +29,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_out_ref, state_ref,
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, s_out_ref, state_ref,
             *, q: int, nc: int):
     ci = pl.program_id(2)
 
@@ -32,41 +45,38 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_out_ref, state_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, 0, 0].astype(jnp.float32)        # (Q, p)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)      # (Q,)
-    A = a_ref[0].astype(jnp.float32)              # ()
-    B = b_ref[0, 0, 0].astype(jnp.float32)        # (Q, n)
-    C = c_ref[0, 0, 0].astype(jnp.float32)        # (Q, n)
+    xdt = xdt_ref[...]                            # (Q, p) f32
+    da = da_ref[...]                              # (1, Q) f32, negative
+    B = b_ref[...].astype(jnp.float32)            # (Q, n)
+    C = c_ref[...].astype(jnp.float32)            # (Q, n)
 
-    dA = dt * A                                   # (Q,) negative
-    cum = jnp.cumsum(dA)                          # (Q,)
-    total = cum[-1]
-
-    # ---- intra-chunk (quadratic in Q) ----
-    cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))      # (Q,Q)
-    diff = cum[:, None] - cum[None, :]                             # (Q,Q)
     iq = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     ik = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(ik <= iq, jnp.exp(diff), 0.0)
-    xdt = x * dt[:, None]                                          # (Q,p)
-    y = jax.lax.dot_general(cb * L, xdt, (((1,), (0,)), ((), ())))
+    causal = ik <= iq
+    # inclusive cumulative decay, as a column (Q, 1) and as a row (1, Q)
+    cum = jnp.sum(jnp.where(causal, da, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(iq == ik, cum, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(da, axis=1, keepdims=True)    # (1, 1)
+
+    # ---- intra-chunk (quadratic in Q) ----
+    cb = _dot(C, B, ((1,), (1,)))                                  # (Q,Q)
+    L = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)
+    y = _dot(cb * L, xdt, ((1,), (0,)))
 
     # ---- inter-chunk ----
     s_prev = state_ref[...]                                        # (n,p)
-    y += jax.lax.dot_general(C * jnp.exp(cum)[:, None], s_prev,
-                             (((1,), (0,)), ((), ())))
+    y += _dot(C * jnp.exp(cum), s_prev, ((1,), (0,)))
 
     # ---- state update ----
-    w = jnp.exp(total - cum)                                       # (Q,)
-    bx = jax.lax.dot_general(B, xdt * w[:, None],
-                             (((0,), (0,)), ((), ())))             # (n,p)
+    w = jnp.exp(total - cum)                                       # (Q,1)
+    bx = _dot(B, xdt * w, ((0,), (0,)))                            # (n,p)
     state_ref[...] = s_prev * jnp.exp(total) + bx
 
-    y_ref[0, 0, 0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _emit_state():
-        s_out_ref[0, 0] = state_ref[...]
+        s_out_ref[...] = state_ref[...]
 
 
 def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 256,
@@ -79,34 +89,35 @@ def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 256,
     g, n = B.shape[2], B.shape[3]
     hpg = h // g
     q = min(chunk, s)
-    assert s % q == 0, (s, q)
+    if s % q:
+        raise ValueError(f"sequence {s} is not a multiple of chunk {q}")
     nc = s // q
 
     # (b, nc, Q, ...) chunked layouts, head-major for clean block addressing
-    xc = x.reshape(b, nc, q, h, p).transpose(0, 3, 1, 2, 4)    # (b,h,nc,Q,p)
-    dtc = dt.reshape(b, nc, q, h).transpose(0, 3, 1, 2)        # (b,h,nc,Q)
-    Bc = B.reshape(b, nc, q, g, n).transpose(0, 3, 1, 2, 4)    # (b,g,nc,Q,n)
+    f32 = jnp.float32
+    dtc = dt.astype(f32).reshape(b, nc, q, h).transpose(0, 3, 1, 2)
+    xdt = x.astype(f32).reshape(b, nc, q, h, p).transpose(0, 3, 1, 2, 4) * \
+        dtc[..., None]                                        # (b,h,nc,Q,p)
+    da = (dtc * A.astype(f32)[None, :, None, None])[:, :, :, None, :]
+    Bc = B.reshape(b, nc, q, g, n).transpose(0, 3, 1, 2, 4)   # (b,g,nc,Q,n)
     Cc = C.reshape(b, nc, q, g, n).transpose(0, 3, 1, 2, 4)
+
+    def chunk_spec(rows, cols, group=False):
+        if group:
+            return pl.BlockSpec((None, None, None, rows, cols),
+                                lambda b_, h_, c_: (b_, h_ // hpg, c_, 0, 0))
+        return pl.BlockSpec((None, None, None, rows, cols),
+                            lambda b_, h_, c_: (b_, h_, c_, 0, 0))
 
     kern = functools.partial(_kernel, q=q, nc=nc)
     y, state = pl.pallas_call(
         kern,
         grid=(b, h, nc),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, q, p),
-                         lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q),
-                         lambda b_, h_, c_: (b_, h_, c_, 0)),
-            pl.BlockSpec((1,), lambda b_, h_, c_: (h_,)),
-            pl.BlockSpec((1, 1, 1, q, n),
-                         lambda b_, h_, c_: (b_, h_ // hpg, c_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q, n),
-                         lambda b_, h_, c_: (b_, h_ // hpg, c_, 0, 0)),
-        ],
+        in_specs=[chunk_spec(q, p), chunk_spec(1, q),
+                  chunk_spec(q, n, group=True), chunk_spec(q, n, group=True)],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, q, p),
-                         lambda b_, h_, c_: (b_, h_, c_, 0, 0)),
-            pl.BlockSpec((1, 1, n, p), lambda b_, h_, c_: (b_, h_, 0, 0)),
+            chunk_spec(q, p),
+            pl.BlockSpec((None, None, n, p), lambda b_, h_, c_: (b_, h_, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, nc, q, p), x.dtype),
@@ -114,6 +125,6 @@ def ssd_chunk_scan(x, dt, A, B, C, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xc, dtc, A, Bc, Cc)
+    )(xdt, da, Bc, Cc)
     y = y.transpose(0, 2, 3, 1, 4).reshape(b, s, h, p)
     return y, state

@@ -23,15 +23,15 @@ from repro.train.loop import make_train_step, train_state_template
 
 f32 = jnp.float32
 
-# long_500k needs sub-quadratic attention; these archs are pure full
-# attention so the cell is skipped (documented in DESIGN.md §Arch-applicability)
+# long_500k needs sub-quadratic attention; every other arch is pure full
+# attention, so its long_500k cell is skipped
 LONG_CONTEXT_ARCHS = ("gemma2-27b", "jamba-v0.1-52b", "mamba2-2.7b")
 
 
 def is_applicable(arch: str, shape: str) -> tuple[bool, str]:
     if shape == "long_500k" and arch not in LONG_CONTEXT_ARCHS:
         return False, ("pure full-attention architecture: 512k KV decode "
-                       "requires sub-quadratic attention (see DESIGN.md)")
+                       "requires sub-quadratic attention")
     return True, ""
 
 

@@ -1,10 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST precede every other import — jax locks the device
-count at first init, and the production meshes need 512 placeholder devices.
+``main`` gives the CPU backend 512 placeholder devices (``XLA_FLAGS``)
+before anything asks jax for a device: jax fixes the device count when
+its backend first starts, and the production meshes need 512.
 
 Per cell this proves:
 * the sharding config is coherent (SPMD partitioning succeeds),
@@ -20,34 +18,36 @@ Usage::
         [--shape NAME|all] [--mesh single|multi|both] [--out DIR]
 """
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import argparse
+import json
+import os
+import time
+import traceback
 
-import jax           # noqa: E402
+import jax
 
-from repro.configs import ARCHS                                   # noqa: E402
-from repro.launch.cells import build_cell, is_applicable          # noqa: E402
-from repro.launch.hlo_analysis import analyze                     # noqa: E402
-from repro.launch.mesh import make_production_mesh, pod_size      # noqa: E402
-from repro.models.config import SHAPE_CELLS                       # noqa: E402
+from repro.configs import ARCHS
+from repro.launch.cells import CellBuild, build_cell, is_applicable
+from repro.launch.hlo_analysis import analyze
+from repro.launch.mesh import make_production_mesh, pod_size
+from repro.models.config import SHAPE_CELLS
 
 
-def _named_shardings(mesh, tree):
-    """PartitionSpec / None pytree -> NamedSharding pytree (old-jax jit)."""
-    from jax.sharding import NamedSharding, PartitionSpec
+def compile_cell(cb: CellBuild, mesh):
+    """Lower + compile one cell on ``mesh`` -> (compiled, lower_s, compile_s).
 
-    def conv(x):
-        if x is None:
-            return NamedSharding(mesh, PartitionSpec())
-        if isinstance(x, PartitionSpec):
-            return NamedSharding(mesh, x)
-        return x
-
-    return jax.tree.map(
-        conv, tree,
-        is_leaf=lambda x: x is None or isinstance(x, PartitionSpec))
+    The cell's shardings are bare ``PartitionSpec``s, resolved against
+    ``mesh`` through ``jax.set_mesh``; so are the model's activation
+    constraints."""
+    t0 = time.time()
+    with jax.set_mesh(mesh):
+        jitted = jax.jit(cb.fn, in_shardings=cb.in_shardings,
+                         out_shardings=cb.out_shardings,
+                         donate_argnums=cb.donate_argnums)
+        lowered = jitted.lower(*cb.args)
+        t1 = time.time()
+        compiled = lowered.compile()
+    return compiled, t1 - t0, time.time() - t1
 
 
 def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
@@ -61,35 +61,14 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
         if save:
             _save(out_dir, rec)
         return rec
-    t0 = time.time()
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         cb = build_cell(arch, shape, mesh, grad_accum=grad_accum)
         if overrides:
             cb = overrides(cb)
-        # jax >= 0.6 accepts bare PartitionSpecs under jax.set_mesh; older
-        # jax wants concrete NamedShardings and enters the Mesh object
-        # itself as the context manager
-        set_mesh = getattr(jax, "set_mesh", None)
-        if set_mesh is None:
-            in_sh = _named_shardings(mesh, cb.in_shardings)
-            out_sh = _named_shardings(mesh, cb.out_shardings)
-            mesh_cm = mesh
-        else:
-            in_sh, out_sh = cb.in_shardings, cb.out_shardings
-            mesh_cm = set_mesh(mesh)
-        with mesh_cm:
-            jitted = jax.jit(cb.fn, in_shardings=in_sh,
-                             out_shardings=out_sh,
-                             donate_argnums=cb.donate_argnums)
-            lowered = jitted.lower(*cb.args)
-            t1 = time.time()
-            compiled = lowered.compile()
-            t2 = time.time()
+        compiled, lower_s, compile_s = compile_cell(cb, mesh)
         mem = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):     # newer jax returns [per-device dict]
-            ca = ca[0] if ca else {}
         hlo = analyze(compiled.as_text(), pod_size(mesh))
         rec.update({
             "ok": True,
@@ -99,8 +78,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
             "attn_hbm_bytes": cb.attn_hbm_bytes,
             "tokens_per_step": cb.cell.global_batch *
             (cb.cell.seq_len if cb.cell.step != "decode" else 1),
-            "lower_s": round(t1 - t0, 2),
-            "compile_s": round(t2 - t1, 2),
+            "lower_s": round(lower_s, 2),
+            "compile_s": round(compile_s, 2),
             "memory": {
                 "argument_bytes": mem.argument_size_in_bytes,
                 "output_bytes": mem.output_size_in_bytes,
@@ -146,6 +125,7 @@ def summarize(rec: dict) -> str:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

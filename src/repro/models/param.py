@@ -48,7 +48,9 @@ def _init_one(spec: ParamSpec, key) -> jax.Array:
         return jnp.ones(spec.shape, dtype)
     if spec.init == "neg_ones":
         return jnp.full(spec.shape, -1, dtype)
-    fan_in = spec.shape[0] if spec.shape else 1
+    # the scan dim that stack_cycle prepends is not an input of the layer
+    dims = [n for n, a in zip(spec.shape, spec.axes) if a != "layers"]
+    fan_in = dims[0] if dims else 1
     std = spec.scale if spec.scale is not None else 1.0 / np.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)
 

@@ -36,7 +36,9 @@ def adamw_init(params):
         "step": jnp.zeros((), jnp.int32),
         "m": jax.tree.map(zeros, params),
         "v": jax.tree.map(zeros, params),
-        "master": jax.tree.map(lambda p: p.astype(f32), params),
+        # a copy even where the param is already f32: the train step
+        # donates params and state, and one buffer cannot be donated twice
+        "master": jax.tree.map(lambda p: jnp.array(p, f32, copy=True), params),
     }
 
 

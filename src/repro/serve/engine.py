@@ -89,8 +89,14 @@ class ServeEngine:
                    cache_len=cache_len)
 
     def generate(self, requests: list, *, temperature: float = 0.0,
-                 seed: int = 0) -> list:
-        """Synchronous batched generation (greedy when temperature == 0)."""
+                 seed: int = 0, return_logits: bool = False):
+        """Synchronous batched generation (greedy when temperature == 0).
+
+        Prompts are left-padded with token ``vocab_size - 1`` to the longest
+        one. With ``return_logits`` the result is ``(outs, logits)``, where
+        ``logits[t]`` is the (batch, vocab) float32 array token ``t`` was
+        sampled from.
+        """
         b = len(requests)
         max_prompt = max(len(r.prompt) for r in requests)
         max_new = max(r.max_new for r in requests)
@@ -109,7 +115,10 @@ class ServeEngine:
         outs = [[] for _ in range(b)]
         pos = jnp.full((b,), max_prompt, jnp.int32)
         tok = self._sample(logits, temperature, key)
+        seen = []
         for step in range(max_new):
+            if return_logits:
+                seen.append(logits)
             for i in range(b):
                 if step < requests[i].max_new:
                     outs[i].append(int(tok[i]))
@@ -121,6 +130,8 @@ class ServeEngine:
             logits, cache = self._step(self.params, cache, tok, pos)
             tok = self._sample(logits, temperature, sub)
             pos = pos + 1
+        if return_logits:
+            return outs, np.asarray(jnp.stack(seen), np.float32)
         return outs
 
     @staticmethod

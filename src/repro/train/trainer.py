@@ -51,9 +51,11 @@ class Trainer:
         self.ckpt = LSTCheckpointManager(
             fs, ckpt_path, fmt=cfg.ckpt_format,
             sync_targets=cfg.sync_targets)
+        # params and optimizer state are donated: the step's outputs reuse
+        # their buffers, so the device holds one copy of the train state
         self.step_fn = jax.jit(make_train_step(
             model, cfg.opt, grad_accum=cfg.grad_accum,
-            ce_chunk=cfg.ce_chunk))
+            ce_chunk=cfg.ce_chunk), donate_argnums=(0, 1))
         self.params = None
         self.opt_state = None
         self.start_step = 0
@@ -61,22 +63,26 @@ class Trainer:
 
     # ------------------------------------------------------------ lifecycle
     def init_or_restore(self, seed: int = 0) -> int:
+        """Restore the newest checkpoint, or start fresh when none exists.
+
+        Only an absent checkpoint starts fresh: a checkpoint that exists
+        but cannot be restored (a missing leaf, a torn chunk) raises.
+        """
         tpl = self.model.param_template()
-        try:
-            fmt = self.cfg.restore_format or self.cfg.ckpt_format
-            shapes = template_shapes(tpl)
-            state_tpl = {"params": shapes,
-                         "opt": _opt_template(shapes)}
-            step, state = self.ckpt.restore_pytree(state_tpl, fmt=fmt)
-            self.params = jax.tree.map(jax.numpy.asarray, state["params"])
-            self.opt_state = jax.tree.map(jax.numpy.asarray, state["opt"])
-            cursor = int(self.ckpt.latest_meta(fmt).get("loader.row", 0))
-            self.loader.load_state_dict({"row": cursor})
-            self.start_step = step + 1
-        except (FileNotFoundError, KeyError):
+        fmt = self.cfg.restore_format or self.cfg.ckpt_format
+        if not self.ckpt.steps(fmt):
             self.params = init_params(tpl, jax.random.PRNGKey(seed))
             self.opt_state = adamw_init(self.params)
             self.start_step = 0
+            return self.start_step
+        shapes = template_shapes(tpl)
+        state_tpl = {"params": shapes, "opt": _opt_template(shapes)}
+        step, state = self.ckpt.restore_pytree(state_tpl, fmt=fmt)
+        self.params = jax.tree.map(jax.numpy.asarray, state["params"])
+        self.opt_state = jax.tree.map(jax.numpy.asarray, state["opt"])
+        cursor = int(self.ckpt.latest_meta(fmt).get("loader.row", 0))
+        self.loader.load_state_dict({"row": cursor})
+        self.start_step = step + 1
         return self.start_step
 
     def save(self, step: int) -> None:

@@ -7,8 +7,8 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# smoke tests and benches must see the single real device, NOT 512 fake ones
-# (the dry-run sets XLA_FLAGS itself, in a subprocess).
+# smoke tests and benches must see the single real device, NOT the 512 fake
+# ones the dry-run's main() asks for.
 os.environ.pop("XLA_FLAGS", None)
 
 

@@ -5,6 +5,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import LSTCheckpointManager
 from repro.data import LakeDataLoader, write_synth_corpus
@@ -157,3 +158,47 @@ def test_loader_prefetch_thread(fs):
     assert b1["inputs"].shape == (2, 16)
     assert b2["cursor"] > b1["cursor"]
     ld.stop()
+
+
+def _trained(fs, root):
+    """A smoke-config trainer that has taken 2 steps and saved step 1."""
+    from repro.configs import smoke_config
+    from repro.models.model import Model
+    from repro.train.trainer import Trainer, TrainerConfig
+    write_synth_corpus(fs, f"{root}/corpus", fmt="delta", n_docs=8,
+                       pack_len=17, vocab=256)
+    model = Model(smoke_config("stablelm-3b"))
+
+    def trainer():
+        loader = LakeDataLoader(fs, f"{root}/corpus", "delta", batch_size=2,
+                                seq_len=16)
+        return Trainer(model, loader, fs, f"{root}/ckpt", TrainerConfig(
+            steps=2, save_every=0, log_every=100, ce_chunk=16))
+
+    tr = trainer()
+    assert tr.init_or_restore() == 0
+    tr.run()
+    return trainer
+
+
+@pytest.mark.parametrize("damage", ["leaf_dropped_from_table",
+                                    "chunk_deleted_from_storage"])
+def test_damaged_checkpoint_raises_instead_of_cold_start(fs, damage):
+    """Only an absent checkpoint starts fresh: one that exists but lost a
+    leaf must stop the restore, not silently reinitialize the weights."""
+    import os
+    root = tempfile.mkdtemp()
+    trainer = _trained(fs, root)
+    mgr = LSTCheckpointManager(fs, f"{root}/ckpt", fmt="hudi",
+                               sync_targets=())
+    victim = [p for p, f in mgr.handle.snapshot().files.items()
+              if f.extra["leaf"] == "params/final_norm/scale"]
+    assert victim
+    if damage == "leaf_dropped_from_table":
+        mgr.handle.commit([], victim, operation="damage")
+        expected = KeyError
+    else:
+        os.remove(f"{root}/ckpt/{victim[0]}")
+        expected = FileNotFoundError
+    with pytest.raises(expected):
+        trainer().init_or_restore()

@@ -88,6 +88,17 @@ def test_full_config_parameter_counts():
         assert lo <= n <= hi, (arch, f"{n:,}")
 
 
+@pytest.mark.parametrize("n_layers", [2, 4])
+def test_stacked_weights_init_at_layer_fan_in(n_layers):
+    """Stacking layers for the scan must not change a weight's init: its
+    fan-in is the layer's input width, not the number of layers."""
+    cfg = replace(get_config("stablelm-3b"), n_layers=n_layers, d_model=512,
+                  d_ff=1024, vocab_size=256)
+    params = init_params(Model(cfg).param_template(), KEY)
+    wq = params["blocks"]["s0"]["attn"]["wq"].astype(jnp.float32)
+    assert abs(float(jnp.std(wq)) * 512 ** 0.5 - 1.0) < 0.05
+
+
 def test_moe_capacity_drops_tokens():
     cfg = replace(smoke_config("dbrx-132b"), dtype="float32",
                   capacity_factor=0.25)

@@ -1,13 +1,8 @@
 """Train-loop numerics, sharding resolver, and HLO cost-model unit tests."""
 
-import subprocess
-import sys
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.parallel.sharding import Sharder
 from repro.train.loop import chunked_cross_entropy
@@ -152,30 +147,6 @@ def test_hlo_walker_matches_xla_on_straightline():
     b = jnp.ones((256, 64))
     compiled = jax.jit(f).lower(a, b).compile()
     res = analyze(compiled.as_text())
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):     # newer jax returns [per-device dict]
-        ca = ca[0]
-    xla = ca["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert abs(res["dot_flops"] - 2 * 128 * 256 * 64) / xla < 0.1
 
-
-@pytest.mark.slow
-def test_dryrun_single_cell_subprocess():
-    """Full dry-run machinery on one small cell, in a subprocess (needs its
-    own XLA_FLAGS before jax init)."""
-    code = (
-        "import os\n"
-        "os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=512'\n"
-        "import sys; sys.path.insert(0, 'src')\n"
-        "from repro.launch.dryrun import run_cell\n"
-        "rec = run_cell('stablelm-3b', 'decode_32k', True, '/tmp/dr', save=False)\n"
-        "assert rec['ok'], rec.get('error')\n"
-        "assert rec['hlo']['flops'] > 0\n"
-        "print('CELL-OK')\n")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd=os.path.dirname(os.path.dirname(
-                             os.path.abspath(__file__))), env=env,
-                         timeout=560)
-    assert "CELL-OK" in out.stdout, out.stderr[-2000:]
