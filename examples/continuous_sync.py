@@ -177,7 +177,8 @@ daemon.close()
 _verify(store)
 
 print("\ndaemon telemetry counters:", {
-    k: v for k, v in telemetry.summary().items() if k.startswith("daemon.")})
+    k: v for k, v in telemetry.summary()["counters"].items()
+    if k.startswith("daemon.")})
 
 # --- the --restart arm: power cut mid-drain, then warm vs cold restart ----
 if args.restart and not interrupted:

@@ -95,29 +95,30 @@ class LSTCheckpointManager:
         import uuid
         t0 = time.perf_counter()
         tag = uuid.uuid4().hex[:8]
-        adds = []
-        for name, leaf in _leaf_paths(pytree):
-            arr = np.asarray(leaf)
-            enc, logical = _encode(arr)
-            flat = enc.reshape(-1)
-            n_shards = max(1, -(-flat.nbytes // MAX_CHUNK_BYTES))
-            per = -(-flat.size // n_shards)
-            for si in range(n_shards):
-                part = flat[si * per:(si + 1) * per]
-                rel = (f"step={step}/{name.replace('/', '.')}"
-                       f"_{si:03d}_{tag}.chunk")
-                meta = chunkfile.write_chunk(
-                    self.fs, self.base, rel, {"tensor": part},
-                    partition_values={"step": str(step)},
-                    extra={"leaf": name, "global_shape": list(arr.shape),
-                           "dtype": logical, "offset": si * per,
-                           "nshards": n_shards})
-                adds.append(meta)
-        stale = [p for p, f in self.handle.snapshot().files.items()
-                 if int(f.partition_values["step"]) == step]
-        commit = self.handle.commit(
-            adds, stale, operation="checkpoint",
-            extra_meta={"step": str(step), **(extra_meta or {})})
+        with self.telemetry.span("save"):
+            adds = []
+            for name, leaf in _leaf_paths(pytree):
+                arr = np.asarray(leaf)
+                enc, logical = _encode(arr)
+                flat = enc.reshape(-1)
+                n_shards = max(1, -(-flat.nbytes // MAX_CHUNK_BYTES))
+                per = -(-flat.size // n_shards)
+                for si in range(n_shards):
+                    part = flat[si * per:(si + 1) * per]
+                    rel = (f"step={step}/{name.replace('/', '.')}"
+                           f"_{si:03d}_{tag}.chunk")
+                    meta = chunkfile.write_chunk(
+                        self.fs, self.base, rel, {"tensor": part},
+                        partition_values={"step": str(step)},
+                        extra={"leaf": name, "global_shape": list(arr.shape),
+                               "dtype": logical, "offset": si * per,
+                               "nshards": n_shards})
+                    adds.append(meta)
+            stale = [p for p, f in self.handle.snapshot().files.items()
+                     if int(f.partition_values["step"]) == step]
+            commit = self.handle.commit(
+                adds, stale, operation="checkpoint",
+                extra_meta={"step": str(step), **(extra_meta or {})})
         self.telemetry.record("ckpt", self.fmt, "save",
                               f"step {step}: {len(adds)} chunks",
                               time.perf_counter() - t0)

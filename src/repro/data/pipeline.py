@@ -9,6 +9,9 @@
 * Straggler mitigation: a background prefetch thread keeps a bounded queue
   of ready batches per host; slow storage reads overlap compute.
 * Multi-host striping: host h of H takes rows where (row_idx % H) == h.
+* Telemetry: the loader reads through an ``InstrumentedFS`` feeding its
+  ``telemetry`` (``storage.get``, ``storage.bytes_read``, ...), and each
+  chunk read is a span ``data.read_chunk``.
 """
 
 from __future__ import annotations
@@ -18,14 +21,19 @@ import threading
 
 import numpy as np
 
+from repro.core.telemetry import Telemetry
+from repro.lst.storage import InstrumentedFS
 from repro.lst.table import LakeTable
 
 
 class LakeDataLoader:
     def __init__(self, fs, base_path: str, fmt: str, *, batch_size: int,
                  seq_len: int, host_id: int = 0, n_hosts: int = 1,
-                 start_row: int = 0, prefetch: int = 2, loop: bool = True):
-        self.table = LakeTable.open(fs, base_path, fmt)
+                 start_row: int = 0, prefetch: int = 2, loop: bool = True,
+                 telemetry: Telemetry | None = None):
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
+        self.table = LakeTable.open(InstrumentedFS(fs, self.telemetry),
+                                    base_path, fmt)
         self.batch_size = batch_size
         self.seq_len = seq_len
         self.host_id = host_id
@@ -47,13 +55,17 @@ class LakeDataLoader:
     def load_state_dict(self, d: dict) -> None:
         self.row = int(d["row"])
 
-    def _fetch_row(self, idx: int) -> np.ndarray:
+    def _read_tokens(self, path: str) -> np.ndarray:
         from repro.lst.chunkfile import read_chunk
+        with self.telemetry.span("data.read_chunk"):
+            cols, _ = read_chunk(self.table.fs, self.table.base, path)
+        return cols["tokens"]
+
+    def _fetch_row(self, idx: int) -> np.ndarray:
         idx %= self.total_rows
         for f, n in zip(self._files, self._rows_per_file):
             if idx < n:
-                cols, _ = read_chunk(self.table.fs, self.table.base, f.path)
-                return cols["tokens"][idx]
+                return self._read_tokens(f.path)[idx]
             idx -= n
         raise IndexError(idx)
 
@@ -74,7 +86,6 @@ class LakeDataLoader:
     def _producer(self) -> None:
         # file-level cache so the producer isn't re-reading chunks per row
         cache: dict[str, np.ndarray] = {}
-        from repro.lst.chunkfile import read_chunk
         while not self._stop.is_set():
             rows = []
             while len(rows) < self.batch_size:
@@ -86,9 +97,7 @@ class LakeDataLoader:
                     for f, n in zip(self._files, self._rows_per_file):
                         if idx < n:
                             if f.path not in cache:
-                                cols, _ = read_chunk(self.table.fs,
-                                                     self.table.base, f.path)
-                                cache[f.path] = cols["tokens"]
+                                cache[f.path] = self._read_tokens(f.path)
                                 if len(cache) > 8:
                                     cache.pop(next(iter(cache)))
                             rows.append(cache[f.path][idx])

@@ -327,9 +327,13 @@ class Model:
         """Final-norm hidden states (b,s,d) + aux loss — no logits
         materialization (the train loss computes chunked vocab projections)."""
         enc_out = self.encode(params, enc_embeds) if self.cfg.encoder else None
-        x = self._embed(params, tokens)
-        x, aux, _ = self._run_blocks(x, params["blocks"], mode="train",
-                                     enc_out=enc_out)
+        # named scopes put the train step's device ops down to a layer in
+        # the profiler trace (op metadata only; the program is unchanged)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens)
+        with jax.named_scope("layers"):
+            x, aux, _ = self._run_blocks(x, params["blocks"], mode="train",
+                                         enc_out=enc_out)
         x = L.apply_norm(x, params["final_norm"], self.cfg)
         # regather the sequence for the (vocab-parallel) chunked loss
         return self.sh(x, "batch", "seq", None), aux

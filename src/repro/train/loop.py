@@ -73,9 +73,10 @@ def make_loss_fn(model: Model, *, ce_chunk: int = 512):
     def loss_fn(params, batch):
         hidden, aux = model.forward_hidden(
             params, batch["inputs"], enc_embeds=batch.get("enc_embeds"))
-        loss, ce, n = chunked_cross_entropy(
-            hidden, model.head_weights(params), batch["targets"],
-            final_softcap=cfg.final_softcap, chunk=ce_chunk)
+        with jax.named_scope("cross_entropy"):
+            loss, ce, n = chunked_cross_entropy(
+                hidden, model.head_weights(params), batch["targets"],
+                final_softcap=cfg.final_softcap, chunk=ce_chunk)
         return loss + aux, {"ce": ce, "aux": aux, "tokens": n}
 
     return loss_fn
@@ -124,8 +125,9 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig, *,
                 micro, (zeros, jnp.zeros((), f32)), mbs)
             grads = pin(jax.tree.map(lambda g: g / grad_accum, gsum))
             loss = lsum / grad_accum
-        new_params, new_opt, om = adamw_update(params, grads, opt_state,
-                                               opt_cfg)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, om = adamw_update(params, grads, opt_state,
+                                                   opt_cfg)
         metrics = {"loss": loss, **om}
         return new_params, new_opt, metrics
 

@@ -12,16 +12,23 @@ here at host scale):
 4. elastic restart: the restored host arrays are ``device_put`` against
    whatever mesh the new job has (the chunk metadata carries global shapes,
    so any device count works).
+
+One :class:`~repro.core.telemetry.Telemetry` rides the run: the loader's
+storage counters, the checkpoint events, a count of JAX lowerings
+(``jax.compiles``) and a span around each part of a step (``train.step``
+holding ``train.batch``, ``train.h2d``, ``train.dispatch``,
+``train.loss_sync`` and ``train.save``), which also lands in a running
+profiler trace.
 """
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 import jax
 
 from repro.checkpoint import LSTCheckpointManager
+from repro.core import Telemetry
 from repro.data import LakeDataLoader
 from repro.models.model import Model
 from repro.models.param import init_params, template_shapes
@@ -44,13 +51,16 @@ class TrainerConfig:
 
 class Trainer:
     def __init__(self, model: Model, loader: LakeDataLoader, fs,
-                 ckpt_path: str, cfg: TrainerConfig = TrainerConfig()):
+                 ckpt_path: str, cfg: TrainerConfig = TrainerConfig(),
+                 telemetry: Telemetry | None = None):
         self.model = model
         self.loader = loader
         self.cfg = cfg
+        self.telemetry = telemetry = telemetry or loader.telemetry
+        telemetry.watch_compiles()
         self.ckpt = LSTCheckpointManager(
             fs, ckpt_path, fmt=cfg.ckpt_format,
-            sync_targets=cfg.sync_targets)
+            sync_targets=cfg.sync_targets, telemetry=telemetry)
         # params and optimizer state are donated: the step's outputs reuse
         # their buffers, so the device holds one copy of the train state
         self.step_fn = jax.jit(make_train_step(
@@ -93,23 +103,31 @@ class Trainer:
     def run(self) -> list:
         if self.params is None:
             self.init_or_restore()
-        t0 = time.perf_counter()
+        span = self.telemetry.span
         for step in range(self.start_step, self.cfg.steps):
-            batch = self.loader.next_batch()
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-            self.params, self.opt_state, metrics = self.step_fn(
-                self.params, self.opt_state, batch)
-            loss = float(metrics["loss"])
-            self.history.append((step, loss))
-            if step % self.cfg.log_every == 0:
-                dt = time.perf_counter() - t0
-                print(f"step {step:5d} loss {loss:.4f} "
-                      f"({dt:.1f}s)", flush=True)
-            if self.cfg.save_every and step and \
-                    step % self.cfg.save_every == 0:
-                self.save(step)
-                self.ckpt.gc()
-        self.save(self.cfg.steps - 1)
+            with span("train.step"):
+                with span("train.batch"):
+                    batch = self.loader.next_batch()
+                with span("train.h2d"):
+                    batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+                with span("train.dispatch"):
+                    self.params, self.opt_state, metrics = self.step_fn(
+                        self.params, self.opt_state, batch)
+                with span("train.loss_sync"):
+                    loss = float(metrics["loss"])
+                self.history.append((step, loss))
+                if step % self.cfg.log_every == 0:
+                    n, seconds = self.telemetry.spans.get("train.step",
+                                                          (0, 0.0))
+                    print(f"step {step:5d} loss {loss:.4f} "
+                          f"({seconds:.1f}s in {n} steps)", flush=True)
+                if self.cfg.save_every and step and \
+                        step % self.cfg.save_every == 0:
+                    with span("train.save"):
+                        self.save(step)
+                        self.ckpt.gc()
+        with span("train.save"):
+            self.save(self.cfg.steps - 1)
         return self.history
 
 
