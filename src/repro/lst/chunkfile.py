@@ -32,7 +32,10 @@ needs two ranged reads (suffix trailer + footer, no ``size`` request) and
 never fetches column data — the Parquet-footer access pattern — while
 :func:`read_chunks_columns` turns the index into *projection pushdown*:
 only the requested columns' ranges are fetched (adjacent ranges coalesced
-into single ranged GETs, all files in one pipelined batch round).
+into single ranged GETs, all files in one pipelined batch round), and
+:func:`read_chunks_rows` goes one step further for an uncompressed
+fixed-width column: row ``i`` is the range ``[off + i * stride, off + (i +
+1) * stride)``, so a reader fetches just the rows it needs.
 
 Layout v2 ("CHK2", still readable) kept the columns inside one msgpack
 body map and its footer carried only ``{nrows, stats}``: no column index,
@@ -128,6 +131,17 @@ class ChunkFooter:
     @property
     def projectable(self) -> bool:
         return self.columns is not None
+
+    def row_stride(self, column: str) -> int | None:
+        """Bytes per row of ``column`` when each of its rows has a byte
+        address (a v3 file, the column in the index, no codec, a fixed-width
+        dtype), else ``None``."""
+        decl = None if self.schema is None else self.schema.get(column)
+        if decl is None or decl.get("codec") or decl["dtype"] == "str" \
+                or self.nrows <= 0:
+            return None
+        length = next(ln for name, _off, ln in self.columns if name == column)
+        return length // self.nrows
 
 
 def _scalar(x):
@@ -495,6 +509,48 @@ def read_chunks_columns(fs, base_path: str, rel_paths: list[str],
         cols, _extra = _parse_full(blob)
         out[i] = (cols, len(blob))
     return out
+
+
+def read_chunks_rows(fs, base_path: str,
+                     requests: list[tuple[str, int, int]], column: str,
+                     footers: list[ChunkFooter],
+                     ) -> tuple[list[np.ndarray], int]:
+    """Row-ranged reads: rows ``[row_lo, row_hi)`` of ``column`` for each
+    ``(rel_path, row_lo, row_hi)`` request, through the v3 column-offset
+    index, without fetching the rest of the column or any other.
+
+    Row ``i`` of a column with a row stride (:meth:`ChunkFooter.row_stride`)
+    is the byte range ``[off + i * stride, off + (i + 1) * stride)``;
+    adjacent or overlapping ranges of one file are coalesced, and every
+    range goes out in ONE pipelined ``read_many_ranges`` round.  A request
+    whose column has no row stride raises ``ValueError``: read that file's
+    column whole with :func:`read_chunks_columns`.
+
+    ``footers`` is aligned with ``requests``.  Returns ``(rows, bytes
+    fetched)``: ``rows[i]`` is request ``i``'s ``(row_hi - row_lo, ...)``
+    array, decoded with the column's dtype and trailing shape.
+    """
+    from repro.lst.storage.base import coalesce_ranges, fetch_many_ranges
+
+    ranges, decls = [], []
+    for (rel_path, lo, hi), ftr in zip(requests, footers):
+        stride = ftr.row_stride(column)
+        if stride is None:
+            raise ValueError(f"{rel_path}: column {column!r} has no row "
+                             "byte addresses (v2 file, codec or strings)")
+        off = next(o for name, o, _ln in ftr.columns if name == column)
+        ranges.append((f"{base_path}/{rel_path}", off + lo * stride,
+                       (hi - lo) * stride))
+        decls.append(ftr.schema[column])
+    merged, slices = coalesce_ranges(ranges)
+    blobs = fetch_many_ranges(fs, merged)
+    rows = []
+    for (_path, lo, hi), decl, (mi, off, ln) in zip(requests, decls, slices):
+        start = off - merged[mi][1]
+        rows.append(np.frombuffer(
+            blobs[mi][start:start + ln], dtype=np.dtype(decl["dtype"]),
+        ).reshape((hi - lo,) + tuple(decl["shape"][1:])))
+    return rows, sum(len(b) for b in blobs)
 
 
 def stats_refute(stats: Mapping[str, ColumnStats], column: str, op: str,

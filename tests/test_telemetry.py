@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import gc
 import os
+import struct
 import subprocess
 import sys
 import tempfile
@@ -18,6 +19,8 @@ import pytest
 from repro.core import telemetry as telmod
 from repro.core.telemetry import COMPILES, Telemetry
 from repro.data import LakeDataLoader, write_synth_corpus
+from repro.lst.storage import InstrumentedFS
+from repro.lst.table import LakeTable
 
 STEP_SPANS = ("train.step", "train.batch", "train.h2d", "train.dispatch",
               "train.loss_sync")
@@ -210,7 +213,8 @@ def test_trainer_run_spans_every_step_and_shares_the_loaders_telemetry(
     inner = sum(spans[k][1] for k in STEP_SPANS[1:])
     assert spans["train.step"][1] >= inner
     assert tr.telemetry.counters["storage.bytes_read"] > 0
-    assert spans["data.read_chunk"][0] == 6               # a read per row
+    assert spans["data.read_rows"][0] == 3               # a read per batch
+    assert tr.telemetry.counters["data.rows_ranged"] == 6
     assert any(e.phase == "save" for e in tr.telemetry.events)
     lines = [ln for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("step")]
@@ -262,31 +266,50 @@ def _delta(tel: Telemetry):
     out.update({k: v - before.get(k, 0) for k, v in tel.counters.items()})
 
 
-def _file_of_row(loader):
-    """Row index -> (path, size) of the file the loader reads it from."""
-    out = []
+ROW_BYTES = 17 * 4                   # a row of the corpus: 17 int32 tokens
+
+
+def _footer_bytes(fs, loader):
+    """Bytes of the two-round footer fetch of every file of the loader:
+    each file's 12-byte trailer, then its footer offset to the end."""
+    total = 0
     for f in loader._files:
-        size = loader.table.fs.inner.size(f"{loader.table.base}/{f.path}")
-        out += [(f.path, size)] * f.record_count
-    return out
+        raw = fs.read_bytes(f"{loader.table.base}/{f.path}")
+        total += 12 + len(raw) - struct.unpack("<Q", raw[-12:-4])[0]
+    return total
 
 
-def test_loader_bytes_read_are_the_files_its_rows_came_from(fs):
-    ld = LakeDataLoader(fs, _corpus(fs), "delta", batch_size=2, seq_len=16)
-    files = _file_of_row(ld)
-    assert len({p for p, _ in files}) == 4                 # 4 files, 4 rows
+def _built(fs, base, **kw):
+    """-> (loader, counter change of building it, counter change of the
+    table open and listing it starts with)."""
+    tel = Telemetry()
+    with _delta(tel) as listing:
+        LakeTable.open(InstrumentedFS(fs, tel), base, "delta").state()
+    with _delta(tel) as built:
+        ld = LakeDataLoader(fs, base, "delta", batch_size=2, seq_len=16,
+                            telemetry=tel, **kw)
+    return ld, built, listing
+
+
+def test_loader_reads_footers_once_then_only_its_rows_bytes(fs):
+    base = _corpus(fs)
+    ld, built, listing = _built(fs, base)
+    assert len(ld._files) == 4                       # 4 files of 4 rows
+    assert built["storage.bytes_read"] == \
+        listing["storage.bytes_read"] + _footer_bytes(fs, ld)
+    assert built["storage.get"] == listing["storage.get"] + 2 * 4
     with _delta(ld.telemetry) as d:
         for _ in range(3):
             ld.next_batch()
-    assert d["storage.bytes_read"] == sum(s for _, s in files[:6])
-    assert d["storage.get"] == 6
-    assert ld.telemetry.spans["data.read_chunk"][0] == 6
+    assert d["storage.bytes_read"] == 6 * ROW_BYTES
+    assert d["storage.get"] == 3                     # a batch's 2 rows: 1 GET
+    assert d["data.rows_ranged"] == 6 and "data.rows_whole" not in d
+    assert ld.telemetry.spans["data.read_rows"][0] == 3
+    assert "data.read_chunk" not in ld.telemetry.spans
 
 
-def test_prefetch_producer_reads_each_file_once_and_counts_it(fs):
-    ld = LakeDataLoader(fs, _corpus(fs), "delta", batch_size=2, seq_len=16,
-                        loop=False)
-    files = _file_of_row(ld)
+def test_prefetch_producer_reads_only_its_rows_bytes_a_get_a_batch(fs):
+    ld, _, _ = _built(fs, _corpus(fs), loop=False)
     with _delta(ld.telemetry) as d:
         ld.start()
         n = 0
@@ -296,9 +319,10 @@ def test_prefetch_producer_reads_each_file_once_and_counts_it(fs):
                 n += 1
         ld.stop()
     assert n == 8
-    assert d["storage.bytes_read"] == sum(s for _, s in dict(files).items())
-    assert d["storage.get"] == 4
-    assert ld.telemetry.spans["data.read_chunk"][0] == 4
+    assert d["storage.bytes_read"] == 16 * ROW_BYTES
+    assert d["storage.get"] == 8
+    assert d["data.rows_ranged"] == 16 and "data.rows_whole" not in d
+    assert ld.telemetry.spans["data.read_rows"][0] == 8
 
 
 def test_loader_takes_the_telemetry_it_is_given(fs):
